@@ -4,8 +4,8 @@ The federation tier's end-to-end check, one box, real process
 boundaries (``python -m repro.serve_filter.fleet.host`` subprocesses
 reached over ``multiprocessing.connection`` sockets):
 
-* every routed answer is checked BIT-IDENTICAL to a single-host
-  in-process oracle ``FilterServer`` serving the same fleet — through
+* every routed answer is checked BIT-IDENTICAL to a single oracle
+  host serving the whole fleet — through
   steady replicated traffic, a LIVE REBALANCE (admit-on-target ->
   SERVING -> drain-on-source, under traffic), and a MID-RUN HOST KILL
   (SIGKILL; replica failover keeps answering);
@@ -15,6 +15,13 @@ reached over ``multiprocessing.connection`` sockets):
   placements (tenants x replicas + rebalance admits), per-block
   planned replica picks, and every diverted block, then requires the
   router's own counters to match.
+
+The bench's own process never starts a JAX backend, because on a TPU machine
+a chip belongs to one process: the fleet is fitted in a child process
+pinned to chip 0, the oracle is a host pinned to chip 0 once that child
+has exited, and host ``h<i>`` is pinned to chip ``i + 1`` (off a TPU
+the pinning is inert). On a TPU machine the bench therefore needs
+``--hosts`` + 1 chips.
 
 Usage::
 
@@ -27,6 +34,7 @@ Appends one entry per run to ``BENCH_fleet_router.json`` (same
 trajectory format as ``serve_filter_bench``).
 """
 import argparse
+import multiprocessing
 import os
 import sys
 import tempfile
@@ -40,10 +48,9 @@ from serve_filter_bench import (_env_fields, _query_pool, fit_fleet,
                                 record)
 
 from repro.core import existence
-from repro.serve_filter import (FilterServer, ReliabilityConfig,
-                                ServeConfig, TenantSpec)
+from repro.serve_filter import ReliabilityConfig, TenantSpec
 from repro.serve_filter.fleet import (FilterRouter, SocketTransport,
-                                      launch_host)
+                                      chip_env, launch_host)
 
 _DEFAULT_JSON = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "BENCH_fleet_router.json")
@@ -88,6 +95,17 @@ class _Accounting:
         return pick
 
 
+def _fit_and_save(tenants: int, steps: int, ckpt: str) -> tuple:
+    """Child process on chip 0: fit the fleet and save every tenant's
+    checkpoint under ``ckpt``. Returns each tenant's relation (for the
+    query pools) and the device context the rows are stamped with."""
+    os.environ.update(chip_env(0))
+    fleet, _ = fit_fleet(tenants, steps=steps)
+    for name, (_, idx) in fleet.items():
+        existence.save_index(os.path.join(ckpt, name), idx, step=0)
+    return {name: ds for name, (ds, _) in fleet.items()}, _env_fields(None)
+
+
 def _traffic_leg(router, oracle, fleet, acct, *, rows_per_request: int,
                  rounds: int, seed: int, dead: set) -> dict:
     """One measured leg: every tenant gets ``rounds`` blocks; every
@@ -96,13 +114,15 @@ def _traffic_leg(router, oracle, fleet, acct, *, rows_per_request: int,
     blocks = rows = 0
     t0 = time.perf_counter()
     for r in range(rounds):
-        for name, (ds, _) in fleet.items():
+        for name, ds in fleet.items():
             pool = _query_pool(ds, k, seed=seed + r)
             acct.planned(router, name, dead)
             got = router.query(name, pool)
-            want = oracle.submit(name, pool).result()
+            want = oracle.request({"op": "query", "tenant": name,
+                                   "ids": pool})
+            assert want["ok"], want
             assert got.shape == (k,), "dropped rows in routed answer"
-            assert np.array_equal(got, np.asarray(want)), \
+            assert np.array_equal(got, want["answers"]), \
                 f"routed answers for {name!r} diverge from the oracle"
             blocks += 1
             rows += k
@@ -113,27 +133,32 @@ def _traffic_leg(router, oracle, fleet, acct, *, rows_per_request: int,
 
 def run(*, hosts: int, tenants: int, replicas: int,
         rows_per_request: int, rounds: int, steps: int,
-        seed: int) -> List[dict]:
+        seed: int) -> tuple:
+    """Returns ``(rows, env)``: the per-leg rows and the device context
+    of the fitting process."""
     assert hosts >= 2, "the fleet bench needs at least two hosts"
     replicas = min(replicas, hosts)
-    fleet, _bases = fit_fleet(tenants, steps=steps)
     ckpt = tempfile.mkdtemp(prefix="fleet-bench-ckpt-")
-    for name, (_, idx) in fleet.items():
-        existence.save_index(os.path.join(ckpt, name), idx, step=0)
-
-    # the single-host oracle: same fleet, one in-process server
-    oracle = FilterServer(ServeConfig())
-    for name in fleet:
-        oracle.admit(TenantSpec(name, checkpoint=ckpt))
+    with multiprocessing.get_context("spawn").Pool(1) as pool:
+        fleet, env = pool.apply(_fit_and_save, (tenants, steps, ckpt))
 
     procs: Dict[str, object] = {}
     router = None
+    oracle = None
     rows_out: List[dict] = []
     try:
+        # the single-host oracle: same fleet, one host of its own
+        proc, address = launch_host(name="oracle", chip=0)
+        procs["oracle"] = proc
+        oracle = SocketTransport(address, host="oracle")
+        for name in fleet:
+            reply = oracle.request({"op": "admit", "spec": TenantSpec(
+                name, checkpoint=ckpt).to_wire()})
+            assert reply["ok"], reply
         transports = {}
         for i in range(hosts):
             name = f"h{i}"
-            proc, address = launch_host(name=name)
+            proc, address = launch_host(name=name, chip=i + 1)
             procs[name] = proc
             transports[name] = SocketTransport(address, host=name)
         router = FilterRouter(
@@ -237,12 +262,13 @@ def run(*, hosts: int, tenants: int, replicas: int,
     finally:
         if router is not None:
             router.close(shutdown_hosts=True)
-        oracle.close()
+        if oracle is not None:
+            oracle.close()
         for proc in procs.values():
             if proc.poll() is None:
                 proc.kill()
             proc.wait(timeout=30)
-    return rows_out
+    return rows_out, env
 
 
 def main() -> List[dict]:
@@ -251,11 +277,10 @@ def main() -> List[dict]:
         args.hosts, args.tenants = 2, 6
         args.rounds = min(args.rounds, 3)
         args.steps = min(args.steps, 8)
-    rows = run(hosts=args.hosts, tenants=args.tenants,
-               replicas=args.replicas,
-               rows_per_request=args.rows_per_request,
-               rounds=args.rounds, steps=args.steps, seed=args.seed)
-    env = _env_fields(None)
+    rows, env = run(hosts=args.hosts, tenants=args.tenants,
+                    replicas=args.replicas,
+                    rows_per_request=args.rows_per_request,
+                    rounds=args.rounds, steps=args.steps, seed=args.seed)
     for r in rows:
         for k, v in env.items():
             r.setdefault(k, v)

@@ -144,6 +144,8 @@ import numpy as np                                    # noqa: E402
 
 from repro.core import existence, lmbf                # noqa: E402
 from repro.data import tuples                         # noqa: E402
+from repro.runtime.compile_cache import (             # noqa: E402
+    enable_compile_cache)
 from repro.serve_filter import (FaultConfig,          # noqa: E402
                                 FilterServeError, FilterServer,
                                 Overloaded, ReliabilityConfig,
@@ -837,6 +839,7 @@ def _check_quant_rows(rows: List[dict], *, smoke: bool) -> None:
 
 
 def main():
+    enable_compile_cache()
     rows: List[dict] = []
     if _ARGS.grid == "nf4" and _ARGS.bits != 4:
         raise SystemExit("--grid nf4 requires --bits 4")

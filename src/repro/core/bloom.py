@@ -16,9 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_C1 = jnp.uint32(0xCC9E2D51)
-_C2 = jnp.uint32(0x1B873593)
-_GOLDEN = jnp.uint32(0x9E3779B9)
+_C1 = np.uint32(0xCC9E2D51)
+_C2 = np.uint32(0x1B873593)
+_GOLDEN = np.uint32(0x9E3779B9)
 
 
 def _rotl32(x, r):

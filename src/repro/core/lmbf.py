@@ -116,10 +116,18 @@ def mlp_head(params, cfg: LMBFConfig, x) -> jax.Array:
     gathered per row (the serving ``GroupedExecutor`` stacks many
     tenants' heads and indexes them with a per-row tenant id) — so
     grouped serving stays bit-identical to this reference.
+
+    The hidden GEMMs run at ``Precision.HIGHEST``: at default precision a
+    TPU multiplies fp32 operands in one bf16 pass, so a served score
+    could differ from the fit-time score the fixup filter was built from
+    and a member just above ``tau`` could fall below it (a false
+    negative). On CPU this changes nothing.
     """
     for li in range(len(cfg.hidden)):
-        x = jax.nn.relu(x @ params["dense"][f"w{li}"] +
-                        params["dense"][f"b{li}"])
+        x = jax.nn.relu(
+            jnp.matmul(x, params["dense"][f"w{li}"],
+                       precision=jax.lax.Precision.HIGHEST)
+            + params["dense"][f"b{li}"])
     return (jnp.sum(x * params["dense"]["w_out"][:, 0], axis=-1)
             + params["dense"]["b_out"][0])
 

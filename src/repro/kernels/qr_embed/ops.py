@@ -19,13 +19,15 @@ def default_interpret() -> bool:
 
 
 def qr_embed(ids, table_q, table_r, *, divisor: int, block_n: int = 1024,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """ids: (...,) int32 -> (..., d) compressed-embedding lookup.
 
     Equivalent to ``table_q[ids // divisor] + table_r[ids % divisor]``
     with the tables VMEM-pinned and the gather executed as one-hot MXU
     matmuls (see qr_embed.py).
     """
+    if interpret is None:
+        interpret = default_interpret()
     shape = ids.shape
     flat = ids.reshape(-1)
     out = qr_embed_call(flat, table_q, table_r, divisor=divisor,

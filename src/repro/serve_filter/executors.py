@@ -68,7 +68,6 @@ and no misanswered in-flight rows, on every placement.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import time
 from typing import Callable, Dict, Optional, Tuple
 
@@ -84,13 +83,6 @@ from repro.nn.spec import is_spec
 from repro.serve_filter.plan import (GroupKey, PROBE_KERNEL, QueryPlan,
                                      quantize_index)
 from repro.sharding import rules
-from repro.sharding.pipeline import shard_map
-
-# shard_map's replication-check kwarg has been renamed across JAX
-# versions (check_rep -> check_vma); resolve once, like the shims in
-# sharding/pipeline.py.
-_CHECK_KW = next((kw for kw in ("check_rep", "check_vma")
-                  if kw in inspect.signature(shard_map).parameters), None)
 
 
 # ================================================================ telemetry
@@ -208,10 +200,7 @@ class Executor:
 
     def program_count(self) -> int:
         """Live jit-cache entries (plan-shape x bucket XLA programs)."""
-        try:
-            return self.fn._cache_size()
-        except AttributeError:      # older/newer jit internals
-            return 0
+        return self.fn._cache_size()
 
 
 # ===================================================================== core
@@ -219,16 +208,12 @@ class Executor:
 # program builders
 
 def _shard_wrap(mesh: Mesh, body, in_specs, out_specs, *,
-                check_rep: bool):
+                check_vma: bool):
     """The sharded placement's program wrapper: ``jit(shard_map(...))``
-    with the replication-check kwarg resolved for this JAX version
-    (``check_rep=False`` for the Pallas probe flavor — pallas_call has
+    (``check_vma=False`` for the Pallas probe flavor — pallas_call has
     no replication rule)."""
-    kw = {}
-    if _CHECK_KW:
-        kw[_CHECK_KW] = check_rep
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=check_vma))
 
 
 def _tenant_param_specs(plan: QueryPlan, mesh: Mesh):
@@ -426,7 +411,7 @@ def _tenant_program(plan: QueryPlan, mesh: Optional[Mesh]):
     return _shard_wrap(mesh, body,
                        (param_specs, P(axis), P(), P()),
                        (P(), P(), P()),
-                       check_rep=plan.probe != PROBE_KERNEL)
+                       check_vma=plan.probe != PROBE_KERNEL)
 
 
 def _place_local(plan: QueryPlan,
@@ -723,13 +708,17 @@ def _grouped_program(key: GroupKey, mesh: Optional[Mesh]):
             # GEMM — bit-equal to the local matmul (row count does
             # not change the k-reduction order; property-tested),
             # and ~10x faster than per-row weight gathers, which
-            # turn the dense stack into pure memory traffic
+            # turn the dense stack into pure memory traffic. Full fp32
+            # precision, as in lmbf.mlp_head: the fixup filter only
+            # covers the scores the fit computed
             for li in range(len(cfg_.hidden)):
                 w = tiles[f"w{li}"]                 # (g, prev, width)
                 b = tiles[f"b{li}"]                 # (g, width)
                 x = x.reshape(-1, tile, x.shape[-1])
                 x = jax.nn.relu(
-                    jnp.einsum("gti,gio->gto", x, w) + b[:, None, :])
+                    jnp.einsum("gti,gio->gto", x, w,
+                               precision=jax.lax.Precision.HIGHEST)
+                    + b[:, None, :])
                 x = x.reshape(-1, x.shape[-1])
             # output layer: the same multiply+reduce as
             # lmbf.mlp_head. The weight row is gathered per TILE
@@ -776,7 +765,7 @@ def _grouped_program(key: GroupKey, mesh: Optional[Mesh]):
                 P(axis),                                      # bits
                 P(), P(), P(), P(), P())
     fused = _shard_wrap(mesh, fused_body, in_specs, (P(), P(), P()),
-                        check_rep=key.probe != PROBE_KERNEL)
+                        check_vma=key.probe != PROBE_KERNEL)
     return fused, gather_tiles
 
 
@@ -860,10 +849,7 @@ class GroupedExecutor:
 
     def program_count(self) -> int:
         """Live jit-cache entries ((arena-shape x bucket) programs)."""
-        try:
-            return self.fn._cache_size()
-        except AttributeError:
-            return 0
+        return self.fn._cache_size()
 
 
 # --------------------------------------------------------------- registry

@@ -17,7 +17,8 @@ Module map (one concern per module, mirroring the serving package):
   replica fan-out, failover/recovery, lifecycle-driven rebalance, and
   the pinned ``router_*`` snapshot.
 """
-from repro.serve_filter.fleet.host import HostAgent, launch_host, run_host
+from repro.serve_filter.fleet.host import (HostAgent, chip_env,
+                                           launch_host, run_host)
 from repro.serve_filter.fleet.ring import HashRing
 from repro.serve_filter.fleet.router import (ROUTER_SNAPSHOT_KEYS,
                                              FilterRouter, RouterStats)
@@ -33,7 +34,7 @@ from repro.serve_filter.fleet.wire import (WIRE_SCHEMA_VERSION, WireError,
 
 __all__ = [
     "FilterRouter", "RouterStats", "ROUTER_SNAPSHOT_KEYS",
-    "HashRing", "HostAgent", "run_host", "launch_host",
+    "HashRing", "HostAgent", "run_host", "launch_host", "chip_env",
     "HostTransport", "InProcessTransport", "SocketTransport",
     "HostUnreachable", "DEFAULT_AUTHKEY",
     "WIRE_SCHEMA_VERSION", "WireError",

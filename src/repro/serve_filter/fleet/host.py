@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import argparse
 import os
+import select
 import subprocess
 import sys
 from multiprocessing import connection
@@ -34,13 +35,15 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve_filter.config import ServeConfig, TenantState
 from repro.serve_filter.faults import FilterServeError
 from repro.serve_filter.fleet import wire
 from repro.serve_filter.fleet.transport import DEFAULT_AUTHKEY
 from repro.serve_filter.server import FilterServer
 
-__all__ = ["HostAgent", "run_host", "launch_host", "READY_PREFIX"]
+__all__ = ["HostAgent", "run_host", "launch_host", "chip_env",
+           "READY_PREFIX"]
 
 READY_PREFIX = "FLEET_HOST_LISTENING"
 
@@ -146,22 +149,40 @@ def run_host(port: int = 0, *, config: Optional[ServeConfig] = None,
     agent.server.close()
 
 
+def chip_env(chip: int) -> Dict[str, str]:
+    """Environment that makes a process see exactly TPU chip ``chip`` of
+    this host, as a one-chip slice of its own (libtpu's per-process chip
+    visibility). Each such process needs its own ``TPU_PROCESS_PORT``.
+    On a four-chip v5e host, processes pinned to different chips start
+    and run side by side without ``ALLOW_MULTIPLE_LIBTPU_LOAD``."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(8476 + chip)}
+
+
 def launch_host(*, config: Optional[ServeConfig] = None,
                 name: str = "host",
                 authkey: bytes = DEFAULT_AUTHKEY,
-                timeout_s: float = 60.0
+                timeout_s: float = 60.0,
+                chip: Optional[int] = None
                 ) -> Tuple[subprocess.Popen, Tuple[str, int]]:
-    """Spawn a subprocess host and wait for its ready line.
+    """Spawn a subprocess host and wait up to ``timeout_s`` for its ready
+    line (``TimeoutError`` after killing it, if none came).
 
     Returns ``(proc, address)``; the caller owns the process (pair it
     with a ``shutdown`` op or ``proc.kill()``). The child gets this
-    interpreter and a ``PYTHONPATH`` that can resolve ``repro``."""
+    interpreter and a ``PYTHONPATH`` that can resolve ``repro``. With
+    ``chip`` it sees only that TPU chip (:func:`chip_env`): a chip
+    belongs to one process, so hosts sharing a machine each need one."""
     import repro
     # repro may be a namespace package (__file__ is None): resolve the
     # src dir from its search path instead
     src_dir = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
     env = dict(os.environ)
     env["PYTHONPATH"] = src_dir + os.pathsep + env.get("PYTHONPATH", "")
+    if chip is not None:
+        env.update(chip_env(chip))
     cmd = [sys.executable, "-m", "repro.serve_filter.fleet",
            "--port", "0", "--name", name]
     if config is not None:
@@ -169,9 +190,16 @@ def launch_host(*, config: Optional[ServeConfig] = None,
     proc = subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
                             text=True)
     assert proc.stdout is not None
+    ready, _, _ = select.select([proc.stdout], [], [], timeout_s)
+    if not ready:
+        proc.kill()
+        proc.wait()
+        raise TimeoutError(f"host {name!r} printed no ready line within "
+                           f"{timeout_s} s")
     line = proc.stdout.readline()
     if not line.startswith(READY_PREFIX):
         proc.kill()
+        proc.wait()
         raise RuntimeError(f"host {name!r} failed to start "
                            f"(got {line!r})")
     port = int(line.split()[1])
@@ -189,6 +217,7 @@ def main(argv=None) -> None:
                         help="wire-form ServeConfig JSON "
                              "(default: ServeConfig())")
     args = parser.parse_args(argv)
+    enable_compile_cache()
     config = None
     if args.config:
         config = wire.config_from_wire(wire.loads(args.config))

@@ -24,28 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map only exists from 2025-era JAX; older releases ship it
-# under jax.experimental. Resolve once at import time. Public: the
-# serving executors (repro.serve_filter.executors) reuse these shims.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
-
-_shard_map = shard_map     # back-compat alias
-
-
-def mark_varying(x, axis: str):
-    """Mark a shard_map carry as axis-varying where the JAX version
-    distinguishes varying from replicated loop carries (jax.lax.pcast,
-    new-style shard_map); a no-op on versions without that type system."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis,), to="varying")
-    return x
-
-
-_mark_varying = mark_varying     # back-compat alias
-
 
 def stage_split(n_layers: int, n_stages: int):
     """Contiguous [start, stop) layer ranges per stage."""
@@ -98,9 +76,10 @@ def pipeline_apply(stack_params, layer_fn: Callable, x, *, mesh: Mesh,
         n_steps = n_micro + n_stages - 1
         # carries become pipe-varying after the first ppermute — mark
         # the initial values varying so the loop carry types match
-        out = _mark_varying(jnp.zeros_like(x_all), axis)
-        cur = _mark_varying(
-            jnp.zeros((mb,) + x_all.shape[1:], x_all.dtype), axis)
+        out = jax.lax.pcast(jnp.zeros_like(x_all), (axis,), to="varying")
+        cur = jax.lax.pcast(
+            jnp.zeros((mb,) + x_all.shape[1:], x_all.dtype), (axis,),
+            to="varying")
 
         def step(t, state):
             cur, out = state
@@ -135,9 +114,12 @@ def pipeline_apply(stack_params, layer_fn: Callable, x, *, mesh: Mesh,
     params_sharded = jax.tree.map(
         lambda p: p.reshape((n_stages, per_stage) + p.shape[1:]),
         stack_params)
-    fn = _shard_map(
+    fn = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
     )
-    return fn(params_sharded, x)
+    # the reshape above runs eagerly on the default device; under the
+    # mesh context the shard_map call places its operands on the mesh
+    with jax.set_mesh(mesh):
+        return fn(params_sharded, x)

@@ -3,6 +3,7 @@ preemption, straggler detection, resumable data pipeline."""
 import os
 import signal
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +13,7 @@ import pytest
 from repro.checkpoint import CheckpointManager, latest_step, restore, save
 from repro.data.lm_pipeline import LMStream, LMStreamConfig
 from repro.runtime import Heartbeat, PreemptionGuard, StepTimer, Watchdog
+from repro.runtime import compile_cache
 
 
 def _tree(seed=0, dtype=jnp.float32):
@@ -145,3 +147,38 @@ def test_lm_stream_host_sharding():
                                  seed=1, n_hosts=2, host_id=1))
     assert not np.array_equal(h0.batch_at(0)["tokens"],
                               h1.batch_at(0)["tokens"])
+
+
+class _RecordingConfig:
+    def __init__(self, platforms):
+        self.jax_platforms = platforms
+        self.updates = {}
+
+    def update(self, name, value):
+        self.updates[name] = value
+
+
+@pytest.mark.parametrize("platforms,env_dir", [
+    (None, None), (None, "/elsewhere/cache"), ("cpu", None)])
+def test_compile_cache_placement(monkeypatch, platforms, env_dir):
+    """The cache goes where $JAX_COMPILATION_CACHE_DIR says (and code
+    sets no other), else to <checkout>/.jax_cache; a CPU-held process
+    keeps none."""
+    cfg = _RecordingConfig(platforms)
+    monkeypatch.setattr(compile_cache, "jax",
+                        types.SimpleNamespace(config=cfg))
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+    got = compile_cache.enable_compile_cache()
+    if platforms == "cpu":
+        assert got is None and cfg.updates == {}
+        return
+    want = env_dir or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    assert got == want
+    assert cfg.updates.get("jax_compilation_cache_dir") == (
+        None if env_dir else want)
+    assert cfg.updates["jax_persistent_cache_min_compile_time_secs"] == 0

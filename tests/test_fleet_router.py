@@ -15,6 +15,8 @@ The contracts pinned here:
   for every placement/failover/rebalance event.
 """
 import os
+import sys
+import time
 
 import numpy as np
 import pytest
@@ -26,7 +28,8 @@ from repro.serve_filter import (FilterServer, ReliabilityConfig,
 from repro.serve_filter.faults import FilterServeError
 from repro.serve_filter.fleet import (ROUTER_SNAPSHOT_KEYS, FilterRouter,
                                       HashRing, HostAgent, HostTransport,
-                                      HostUnreachable, InProcessTransport)
+                                      HostUnreachable, InProcessTransport,
+                                      launch_host)
 
 N_HOSTS = 3
 
@@ -369,3 +372,40 @@ def test_router_snapshot_schema_pinned(checkpoints):
     snap = router.stats_snapshot()
     assert set(snap) == ROUTER_SNAPSHOT_KEYS
     assert all(isinstance(v, float) for v in snap.values())
+
+
+# ------------------------------------------------- launch_host (no JAX)
+# A stand-in interpreter: launch_host runs ``sys.executable -m ...``, so
+# a shell script in its place shows what the child was given.
+
+def _fake_interpreter(tmp_path, body: str) -> str:
+    path = tmp_path / "fake-python"
+    path.write_text("#!/bin/sh\n" + body)
+    path.chmod(0o755)
+    return str(path)
+
+
+def test_launch_host_honours_timeout(tmp_path, monkeypatch):
+    monkeypatch.setattr(sys, "executable",
+                        _fake_interpreter(tmp_path, "exec sleep 60\n"))
+    t0 = time.monotonic()
+    with pytest.raises(TimeoutError):
+        launch_host(name="mute", timeout_s=0.5)
+    assert time.monotonic() - t0 < 10
+
+
+def test_launch_host_pins_child_to_one_chip(tmp_path, monkeypatch):
+    out = tmp_path / "env.txt"
+    monkeypatch.setattr(sys, "executable", _fake_interpreter(
+        tmp_path, f'env > "{out}"\necho "FLEET_HOST_LISTENING 4242"\n'))
+    proc, address = launch_host(name="pinned", chip=2, timeout_s=30)
+    assert proc.wait(timeout=30) == 0
+    assert address == ("127.0.0.1", 4242)
+    env = dict(line.split("=", 1)
+               for line in out.read_text().splitlines() if "=" in line)
+    assert env["TPU_VISIBLE_CHIPS"] == "2"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    proc, _ = launch_host(name="unpinned", timeout_s=30)
+    assert proc.wait(timeout=30) == 0
+    assert "TPU_VISIBLE_CHIPS" not in out.read_text()
