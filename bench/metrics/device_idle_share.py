@@ -1,0 +1,8 @@
+"""1 - (union of the device's op intervals) / (traced window), in %."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if tr is None or not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
