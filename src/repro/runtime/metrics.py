@@ -160,6 +160,44 @@ class Histogram:
         self._max = max(self._max, other._max)
         return self
 
+    def copy(self) -> "Histogram":
+        """An independent histogram holding the same samples."""
+        out = Histogram(self.growth, self.min_value)
+        out._counts = dict(self._counts)
+        out.count, out.total = self.count, self.total
+        out._min, out._max = self._min, self._max
+        return out
+
+    def since(self, earlier: "Histogram") -> "Histogram":
+        """The samples recorded after ``earlier`` was copied from this
+        histogram (:meth:`copy`): the percentiles of one window. The
+        window's min/max are known only to their buckets, so they are
+        the occupied buckets' edges, clamped to the observed range."""
+        if (earlier.growth != self.growth
+                or earlier.min_value != self.min_value):
+            raise ValueError("since() needs a copy of this histogram")
+        counts = {}
+        for i, n in self._counts.items():
+            d = n - earlier._counts.get(i, 0)
+            if d:
+                counts[i] = d
+        if (earlier.count > self.count
+                or any(n < 0 for n in counts.values())
+                or any(i not in self._counts for i in earlier._counts)):
+            raise ValueError("since() needs an earlier copy of this "
+                             "histogram, not a later or another one")
+        out = Histogram(self.growth, self.min_value)
+        out._counts = counts
+        out.count = self.count - earlier.count
+        out.total = self.total - earlier.total
+        if counts:
+            lo, hi = min(counts), max(counts)
+            out._min = self._min if lo == 0 else max(
+                self._min, self.min_value * self.growth ** lo)
+            out._max = min(self._max,
+                           self.min_value * self.growth ** (hi + 1))
+        return out
+
     @property
     def min(self) -> float:
         return self._min if self.count else 0.0

@@ -55,6 +55,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import existence, lmbf
+from repro.runtime.trace import NULL_TRACER, Tracer
 from repro.serve_filter.faults import NULL_INJECTOR, FaultInjector
 from repro.serve_filter.plan import GroupKey, quantize_index
 
@@ -67,9 +68,11 @@ class PlanGroupArena:
 
     def __init__(self, key: GroupKey, executor,
                  min_capacity: int = MIN_CAPACITY, mesh=None,
-                 injector: FaultInjector = NULL_INJECTOR):
+                 injector: FaultInjector = NULL_INJECTOR,
+                 tracer: Tracer = NULL_TRACER):
         self.key = key
         self.executor = executor            # GroupedExecutor (owns .fn)
+        self.tracer = tracer                # ``tiles``/``launch`` spans
         # fault-injection sites fire BEFORE any mutation (add/swap) or
         # materialization (device_arrays): an injected fault can fail a
         # hydration or a dispatch but never corrupt arena bookkeeping
@@ -144,6 +147,8 @@ class PlanGroupArena:
         # signature: steady-state traffic repeats tenant layouts, and
         # the gather costs as much as the GEMM it feeds
         self._tile_cache: Dict[bytes, object] = {}
+        self.tile_hits = 0                  # dispatches the cache spared
+        self.tile_misses = 0                # dispatches that gathered
 
     # ------------------------------------------------------------- access
     def __len__(self) -> int:
@@ -450,7 +455,11 @@ class PlanGroupArena:
         ``key.tile_rows``; callers whose n is not tile-aligned get
         padded here (wildcard rows on the last row's slot — a full
         single-tenant batch stays single-tenant) and the outputs
-        sliced back.
+        sliced back. Each call counts as a tile-cache hit or miss
+        (``tile_hits`` / ``tile_misses``); with tracing on, the
+        ``tiles`` span covers the cache lookup (and on a miss the gather
+        and the slot vector's transfer), the ``launch`` span the
+        fused program's dispatch.
         """
         raw = np.asarray(raw_ids, np.int32)
         idx = np.asarray(tenant_idx, np.int32)
@@ -462,19 +471,24 @@ class PlanGroupArena:
             idx = np.concatenate(
                 [idx, np.full(pad, idx[-1] if n else 0, np.int32)])
         params, bits, tau, m_bits, base = self.device_arrays()
-        sig = idx.tobytes()
-        hit = self._tile_cache.get(sig)
-        if hit is None:
-            tile_idx = idx.reshape(-1, self.key.tile_rows)[:, 0]
-            hit = (self.executor.gather_tiles(params,
-                                              jnp.asarray(tile_idx)),
-                   jnp.asarray(idx))
-            if len(self._tile_cache) >= 8:      # bounded: drop arbitrary
-                self._tile_cache.pop(next(iter(self._tile_cache)))
-            self._tile_cache[sig] = hit
+        with self.tracer.span("tiles", cat="detail"):
+            sig = idx.tobytes()
+            hit = self._tile_cache.get(sig)
+            if hit is None:
+                self.tile_misses += 1
+                tile_idx = idx.reshape(-1, self.key.tile_rows)[:, 0]
+                hit = (self.executor.gather_tiles(params,
+                                                  jnp.asarray(tile_idx)),
+                       jnp.asarray(idx))
+                if len(self._tile_cache) >= 8:  # bounded: drop arbitrary
+                    self._tile_cache.pop(next(iter(self._tile_cache)))
+                self._tile_cache[sig] = hit
+            else:
+                self.tile_hits += 1
         tiles, idx_dev = hit
-        out = self.executor.call(params, tiles, bits, tau, m_bits, base,
-                                 idx_dev, raw)
+        with self.tracer.span("launch", cat="detail"):
+            out = self.executor.call(params, tiles, bits, tau, m_bits,
+                                     base, idx_dev, raw)
         if pad:
             out = tuple(o[:n] for o in out)
         return out

@@ -183,9 +183,11 @@ class MetricsConfig:
     (``runtime.trace.Tracer``). ``path``/``echo`` both off means no
     logger is constructed; ``trace`` (or a ``trace_path``) attaches a
     tracer to the scheduler's hot path, bounded to ``trace_events``
-    retained spans. ``server.dump_trace()`` exports Chrome trace-event
-    JSON to ``trace_path`` (or an explicit path) — ``server.close()``
-    dumps automatically when ``trace_path`` is set."""
+    retained spans (about nine a batch). ``server.dump_trace()``
+    exports Chrome trace-event JSON to ``trace_path`` (or an explicit
+    path) — ``server.close()`` dumps automatically when ``trace_path``
+    is set. An enabled tracer also writes its spans into a running
+    ``jax.profiler`` trace (``runtime/trace.py``)."""
     path: Optional[str] = None
     echo: bool = False
     trace: bool = False

@@ -488,7 +488,7 @@ class FilterRegistry:
                 arena = PlanGroupArena(
                     gk, executors_lib.acquire_grouped_executor(
                         gk, self.placement.mesh),
-                    injector=self.injector)
+                    injector=self.injector, tracer=self.tracer)
                 self._groups[gk] = arena
             try:
                 if (prev is not None and prev.group is arena

@@ -49,12 +49,19 @@ per-dispatch latency). Occupancy (valid/padded) is tracked per batch by
 
 Observability: the scheduler takes an optional ``runtime.trace.Tracer``
 and emits one span per pipeline stage — ``prepare`` / ``dispatch`` /
-``device_block`` / ``scatter_retire`` on the host thread, plus a
-``device_compute`` span on a synthetic ``device`` track covering
-dispatch -> materialization. In an exported Chrome trace the async
-double buffer is therefore VISIBLE: prepare-of-batch-*t+1* sits under
-device-compute of batch *t*. Each request's queue time (submit ->
-first dispatch) and end-to-end latency land in ``ServeStats``.
+``device_block`` / ``scatter_retire`` on the host thread (category
+``serve``), plus a ``device_compute`` span on a synthetic ``device``
+track covering dispatch -> materialization. In an exported Chrome trace
+the async double buffer is therefore VISIBLE: prepare-of-batch-*t+1*
+sits under device-compute of batch *t*. Spans of category ``detail``
+split the stages: ``tiles`` (the arena's tile-cache lookup and, on a
+miss, the weight gather) and ``launch`` (the fused program's dispatch)
+inside ``dispatch``, ``stats`` (per-tenant stage sums and
+``record_batch``) inside ``scatter_retire``, and the server's
+``submit`` beside them. Every span also reaches the profiler as the
+annotation ``serve.<name>`` (``runtime/trace.py``). Each request's
+queue time (submit -> first dispatch) and end-to-end latency land in
+``ServeStats``.
 
 Completion surface: callers no longer poll ``QueryRequest.done`` — a
 submission is observed through a :class:`QueryFuture` (``result``,
@@ -716,7 +723,8 @@ class QueryScheduler:
         self.injector.check("dispatch", prep.tenant)
         with self.tracer.span("dispatch", seq=prep.seq,
                               bucket=prep.bucket) as sp:
-            compiles_before = executors.compile_count()
+            # only a live span reads the (process-wide) compile count
+            compiles_before = executors.compile_count() if sp else 0
             if prep.group is not None:
                 outputs = prep.group.run(prep.batch, prep.slots)
             else:
@@ -816,26 +824,28 @@ class QueryScheduler:
                 if off + n >= req.ids.shape[0]:  # last span: req done
                     req._complete(t_done)     # resolves the future too
                     record_request(t_done - req.t_submit)
-            per_tenant: Dict[str, int] = {}
-            # per-tenant stage-positive sums (spans are contiguous row
-            # ranges of the FULL batch, so each slices the full arrays)
-            stages: Dict[str, List[int]] = {}
-            for e, p, (_, _, n) in zip(prep.span_entries, prep.span_pos,
-                                       prep.take):
-                per_tenant[e.tenant] = per_tenant.get(e.tenant, 0) + n
-                acc = stages.get(e.tenant)
-                if acc is None:
-                    acc = stages[e.tenant] = [0, 0, 0, 0]
-                acc[0] += n
-                acc[1] += int(full_model[p:p + n].sum())
-                acc[2] += int(full_backup[p:p + n].sum())
-                acc[3] += int(full_ans[p:p + n].sum())
-            self.stats.record_batch(
-                prep.tenant, prep.n_total, prep.bucket, latency, ans,
-                model, backup, inflight=len(self._inflight),
-                per_tenant=per_tenant,
-                per_tenant_stages={k: tuple(v)
-                                   for k, v in stages.items()})
+            with tracer.span("stats", cat="detail"):
+                per_tenant: Dict[str, int] = {}
+                # per-tenant stage-positive sums (spans are contiguous
+                # row ranges of the FULL batch, so each slices the full
+                # arrays)
+                stages: Dict[str, List[int]] = {}
+                for e, p, (_, _, n) in zip(prep.span_entries,
+                                           prep.span_pos, prep.take):
+                    per_tenant[e.tenant] = per_tenant.get(e.tenant, 0) + n
+                    acc = stages.get(e.tenant)
+                    if acc is None:
+                        acc = stages[e.tenant] = [0, 0, 0, 0]
+                    acc[0] += n
+                    acc[1] += int(full_model[p:p + n].sum())
+                    acc[2] += int(full_backup[p:p + n].sum())
+                    acc[3] += int(full_ans[p:p + n].sum())
+                self.stats.record_batch(
+                    prep.tenant, prep.n_total, prep.bucket, latency, ans,
+                    model, backup, inflight=len(self._inflight),
+                    per_tenant=per_tenant,
+                    per_tenant_stages={k: tuple(v)
+                                       for k, v in stages.items()})
 
     def _next_tenant(self) -> Optional[str]:
         while self._order:

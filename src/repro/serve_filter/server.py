@@ -335,11 +335,12 @@ class FilterServer:
                     ) -> List[QueryFuture]:
         """Bulk admission for fleet clients: ``[(tenant, ids), ...]``
         -> futures, in order. A shared ``deadline_ms`` applies to every
-        request in the batch."""
+        request in the batch. Traced as the ``submit`` span."""
         sched = self.scheduler
-        return [QueryFuture(req, sched)
-                for req in sched.submit_many(items,
-                                             deadline_ms=deadline_ms)]
+        with self.tracer.span("submit", cat="detail"):
+            return [QueryFuture(req, sched)
+                    for req in sched.submit_many(items,
+                                                 deadline_ms=deadline_ms)]
 
     def step(self) -> bool:
         return self.scheduler.step()
@@ -401,6 +402,12 @@ class FilterServer:
         snap["arena_compactions"] = float(sum(a.compactions
                                               for a in arenas))
         snap["arena_growths"] = float(sum(a.growths for a in arenas))
+        # grouped dispatches whose per-tile weight gather the arena's
+        # tile-signature cache spared, and those that paid for it
+        snap["arena_tile_cache_hits"] = float(sum(a.tile_hits
+                                                  for a in arenas))
+        snap["arena_tile_cache_misses"] = float(sum(a.tile_misses
+                                                    for a in arenas))
         snap["trace_events"] = float(len(self.tracer))
         # actual PER-SHARD device footprint of the arenas (padding +
         # growth headroom included) — budget_mb counts nominal

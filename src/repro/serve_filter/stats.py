@@ -35,7 +35,9 @@ Each line is one snapshot. The load-bearing keys:
   / ``compile_ms_total`` (XLA compiles + wall time burned in them),
   ``executor_cache_hits``/``_misses``, and ``arena_*`` gauges (slot
   occupancy, holes, dead bitset words, compactions, growths) for the
-  grouped megabatch arenas.
+  grouped megabatch arenas; ``arena_tile_cache_hits``/``_misses`` count
+  the grouped dispatches whose per-tile weight gather the arena's
+  tile-signature cache spared or paid (always on).
 
 Per-tenant drift
 ----------------
@@ -55,9 +57,14 @@ Counters cannot show OVERLAP. The server's ``MetricsConfig(trace=True)``
 attaches a ``runtime.trace.Tracer`` to the scheduler's hot path;
 ``server.dump_trace(path)`` writes Chrome trace-event JSON — open it at
 https://ui.perfetto.dev. The ``host`` thread shows prepare / dispatch /
-device_block / scatter_retire spans; the synthetic ``device`` track
-shows each batch's compute window. With ``async_dispatch=True`` the
-prepare span of batch *t+1* sits UNDER device-compute of batch *t*.
+device_block / scatter_retire spans, with ``tiles`` / ``launch`` inside
+dispatch, ``stats`` inside scatter_retire and ``submit`` beside them;
+the synthetic ``device`` track shows each batch's compute window. With
+``async_dispatch=True`` the prepare span of batch *t+1* sits UNDER
+device-compute of batch *t*. The same host spans reach a running
+``jax.profiler`` trace as ``serve.<name>`` annotations, on the clock of
+the device's own ops. ``queue_time`` is a full-history histogram; the
+queue waits of one window are ``queue_time.since(copy_at_start)``.
 
 Lifecycle observability: the registry reports every tenant-state
 transition (``ADMITTED -> HYDRATING -> SERVING -> DRAINING ->

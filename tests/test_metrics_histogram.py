@@ -115,6 +115,46 @@ def test_histogram_summary_key_shape():
     assert s["queue_p50_ms"] <= s["queue_p99_ms"] <= s["queue_max_ms"]
 
 
+@pytest.mark.parametrize("seed", range(4))
+def test_histogram_since_is_the_window(seed):
+    """``since`` of an earlier ``copy`` holds exactly the samples
+    recorded after the copy: bucket counts equal to a histogram of
+    those samples alone, percentiles within ``growth`` of theirs."""
+    rng = np.random.default_rng(seed)
+    before = _random_samples(rng, int(rng.integers(0, 300)))
+    window = _random_samples(rng, int(rng.integers(1, 300)))
+    h = Histogram(growth=1.1)
+    for v in before:
+        h.record(v)
+    mark = h.copy()
+    for v in window:
+        h.record(v)
+    alone = Histogram(growth=1.1)
+    for v in window:
+        alone.record(v)
+    got = h.since(mark)
+    assert got._counts == alone._counts
+    assert got.count == len(window)
+    assert got.total == pytest.approx(sum(window))
+    for q in (1, 50, 90, 99, 100):
+        exact = _exact_nearest_rank(window, q)
+        assert exact / 1.1 <= got.percentile(q) <= exact * 1.1
+    assert mark.count == len(before)          # the copy is independent
+
+
+def test_histogram_since_refuses_a_later_or_foreign_copy():
+    h = Histogram()
+    h.record(0.5)
+    later = h.copy()
+    later.record(2.0)
+    with pytest.raises(ValueError):
+        h.since(later)
+    with pytest.raises(ValueError):
+        h.since(Histogram(growth=1.2))
+    assert h.since(h.copy()).count == 0
+    assert h.since(h.copy()).percentile(90) == 0.0
+
+
 def test_histogram_validates_parameters():
     with pytest.raises(ValueError):
         Histogram(growth=1.0)

@@ -84,6 +84,7 @@ SNAPSHOT_KEYS = {
     "executor_cache_hits", "executor_cache_misses",
     "arena_holes", "arena_dead_words", "arena_slot_occupancy",
     "arena_compactions", "arena_growths", "arena_mb", "arena_host_mb",
+    "arena_tile_cache_hits", "arena_tile_cache_misses",
     "trace_events",
     # compressed arenas (quantized tenant state)
     "arena_quant_mb", "tenants_per_gb",
@@ -372,3 +373,94 @@ def test_dump_trace_requires_path(fleet):
     srv = FilterServer(ServeConfig.from_kwargs(trace=True))
     with pytest.raises(ValueError, match="trace path"):
         srv.dump_trace()
+
+
+# ------------------------------------------- spans, counters, profiler
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_dispatch_reads_compile_count_only_when_traced(fleet, monkeypatch,
+                                                       trace):
+    """The ``compiled`` arg of the dispatch span is the only reader of
+    the process-wide compile count on the hot path: with tracing off a
+    dispatch never sums it."""
+    calls = []
+    real = executors_lib.compile_count
+
+    def counted():
+        calls.append(1)
+        return real()
+
+    ds, idx = fleet["alpha"]
+    srv = FilterServer(ServeConfig.from_kwargs(grouped=True, trace=trace))
+    srv.admit(TenantSpec("alpha", index=idx))
+    monkeypatch.setattr(executors_lib, "compile_count", counted)
+    srv.submit_many([("alpha", _probes(ds, 32, seed=8))])
+    srv.run_until_drained()
+    assert srv.stats.totals.batches == 1
+    assert (len(calls) > 0) is trace
+
+
+def test_tile_cache_counters(fleet):
+    """One dispatch counts once: a layout seen before hits, a new one
+    misses, and a mutation of the arena (``_touch``) clears the cache
+    so the next dispatch misses even on a layout seen before."""
+    ds, idx = fleet["alpha"]
+    srv = FilterServer(ServeConfig.from_kwargs(grouped=True))
+    for t in ("a1", "a2"):
+        srv.admit(TenantSpec(t, index=idx))
+    (arena,) = srv.registry.groups.values()
+
+    def dispatch(*tenants):
+        srv.submit_many([(t, _probes(ds, 16, seed=9)) for t in tenants])
+        srv.run_until_drained()
+        return arena.tile_hits, arena.tile_misses
+
+    assert dispatch("a1") == (0, 1)
+    assert dispatch("a1") == (1, 1)           # same layout
+    assert dispatch("a1", "a2") == (1, 2)     # a megabatch: new layout
+    assert dispatch("a1", "a2") == (2, 2)
+    srv.admit(TenantSpec("a3", index=idx))    # mutation: cache cleared
+    assert dispatch("a1") == (2, 3)
+    assert dispatch("a1") == (3, 3)
+    snap = srv.stats_snapshot()
+    assert snap["arena_tile_cache_hits"] == 3.0
+    assert snap["arena_tile_cache_misses"] == 3.0
+
+
+SERVE_SPANS = {"serve.submit", "serve.prepare", "serve.dispatch",
+               "serve.tiles", "serve.launch", "serve.device_block",
+               "serve.scatter_retire", "serve.stats"}
+
+
+def test_spans_reach_the_profiler_host_plane(fleet, tmp_path):
+    """A traced server under ``jax.profiler`` puts every one of its
+    spans on the profiler's ``/host:CPU`` plane as ``serve.<name>``;
+    the ring keeps the four ``serve``-category stages apart from the
+    ``detail`` spans inside and beside them."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    ds, idx = fleet["alpha"]
+    srv = FilterServer(ServeConfig.from_kwargs(grouped=True, trace=True))
+    for t in ("a1", "a2"):
+        srv.admit(TenantSpec(t, index=idx))
+    srv.submit_many([("a1", _probes(ds, 16, seed=1))])
+    srv.run_until_drained()                   # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        srv.submit_many([(t, _probes(ds, 16, seed=2))
+                         for t in ("a1", "a2")])
+        srv.run_until_drained()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events}
+    assert SERVE_SPANS <= names
+    cats = {s.name: s.cat for s in srv.tracer.events()
+            if s.name != "device_compute"}
+    assert {n for n, c in cats.items() if c == "serve"} == {
+        "prepare", "dispatch", "device_block", "scatter_retire"}
+    assert {n for n, c in cats.items() if c == "detail"} == {
+        "submit", "tiles", "launch", "stats"}

@@ -129,6 +129,73 @@ def test_disabled_span_is_shared_singleton():
     assert tr.span("a") is tr.span("b")     # no per-call allocation
 
 
+# ------------------------------------------------------- profiler sink
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_opens_one_profiler_annotation_iff_enabled(enabled):
+    """Each span of an enabled tracer holds ``serve.<name>`` open for
+    its duration (bare name: args stay out of the profiler's event
+    name); a disabled tracer opens none, and ``add`` never does."""
+    log = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+            log.append(("open", name))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("close", self.name))
+            return False
+
+    tr = Tracer(enabled=enabled)
+    tr._note_cls = Note
+    with tr.span("prepare", rows=3):
+        with tr.span("tiles", cat="detail"):
+            pass
+    tr.add("device_compute", 0.0, 1.0, track="device")
+    if enabled:
+        assert log == [("open", "serve.prepare"), ("open", "serve.tiles"),
+                       ("close", "serve.tiles"), ("close", "serve.prepare")]
+        assert [s.name for s in tr.events()] == ["tiles", "prepare",
+                                                 "device_compute"]
+    else:
+        assert log == [] and len(tr) == 0
+
+
+def test_annotation_closes_when_the_span_raises():
+    closed = []
+
+    class Note:
+        def __init__(self, name):
+            pass
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            closed.append(exc[0])
+            return False
+
+    tr = Tracer()
+    tr._note_cls = Note
+    with pytest.raises(KeyError):
+        with tr.span("dispatch"):
+            raise KeyError("x")
+    assert closed == [KeyError] and len(tr) == 1
+
+
+def test_profiler_annotation_is_imported_by_the_first_span():
+    from jax.profiler import TraceAnnotation
+    tr = Tracer()
+    assert tr._note_cls is None
+    with tr.span("x"):
+        pass
+    assert tr._note_cls is TraceAnnotation
+
+
 # --------------------------------------------------------- Chrome export
 
 def test_chrome_trace_round_trip(tmp_path):
