@@ -3,17 +3,20 @@
 precision, through the same comparison that decides ``correct``.
 
     python3 bench/control.py --workload <cell> --seeds a,b,c \\
-        --seconds <s> [--precisions bf16,high,highest]
+        --seconds <s> [--precisions bf16,high,highest,tau]
 
 For each seed it makes the cell's data exactly as ``bench/run.py`` does
 (relations, filters, pools, the window's schedule), answers every row
 of every request in the schedule with the reference's forward pass on
 the device at each precision (``bf16``: the hidden GEMM in one bfloat16
 pass, what the chip does to float32 at default precision; ``high``:
-three passes; ``highest``: float32), and prints the compared numbers
-beside their limits, one JSON line per seed and precision, with the
-largest logit error against the float64 reference. ``correct`` must
-come out false for ``bf16``. No server runs: this reads the control's
+three passes; ``highest``: float32), on the weights the program serves
+(a quantized configuration's dequantized), and prints the compared
+numbers beside their limits, one JSON line per seed and precision, with
+the largest logit error against the float64 reference. ``correct`` must
+come out false for ``bf16``. ``tau``, for a quantized configuration, is
+``highest`` thresholded at the float32 ``tau`` in place of ``tau_q``;
+it must come out false too. No server runs: this reads the control's
 numbers, not the program's.
 """
 from __future__ import annotations
@@ -31,7 +34,7 @@ sys.path[:0] = [os.path.dirname(HERE), os.path.join(os.path.dirname(HERE),
 def control_numbers(cfg, mix, seed: int, seconds: float, precisions):
     """``{precision: numbers}`` for one seed (see ``check.compare``)."""
     import numpy as np
-    from bench.lib import cell, check, filters, traffic
+    from bench.lib import cell, check, filters, quant, traffic
     made = [cell.make_filter(cfg, seed, r)
             for r in range(int(cfg["relations"]))]
     rels, filts = [m[0] for m in made], [m[1] for m in made]
@@ -45,9 +48,13 @@ def control_numbers(cfg, mix, seed: int, seconds: float, precisions):
     n = len(sched)
     out = {}
     for prec in precisions:
-        lg = [filters.logits_at(f.params, f.cols, rows, prec)
+        lg = [filters.logits_at(f.served, f.cols, rows,
+                                "highest" if prec == "tau" else prec)
               for f, rows in zip(filts, pools)]
-        got = cell.reference(filts, pools, is_record, tenants, sched, lg)
+        got = cell.reference(
+            filts, pools, is_record, tenants, sched, lg,
+            thresholds=[quant.threshold(f.tau) for f in filts]
+            if prec == "tau" else None)
         numbers = check.compare(got.answers, np.ones(n, bool),
                                 np.zeros(n, bool), np.arange(n), sched.rows,
                                 ref)

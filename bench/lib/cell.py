@@ -16,11 +16,11 @@ records inserted.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from bench.lib import filters, relation
+from bench.lib import filters, quant, relation
 
 
 def sub_seed(seed: int, *stream: int) -> int:
@@ -42,7 +42,10 @@ def relation_of(config: Dict):
 def make_filter(config: Dict, seed: int, r: int
                 ) -> Tuple[relation.Relation, filters.Filter]:
     """Synthesize relation ``r`` and fit its filter; the fixup bitset
-    has the configuration's fixed size."""
+    has the configuration's fixed size. A quantized configuration's
+    filter is also quantized and calibrated here, once per relation
+    (``quant``), and its fixup filter holds every key that the float32
+    model rejects at ``tau`` or the quantized one at ``tau_q``."""
     rc, m, t = config["relation"], config["model"], config["train"]
     rel = relation.synthesize(rc["cards"], int(rc["records"]),
                               sub_seed(seed, 1, r), rc["zipf_a"],
@@ -60,13 +63,28 @@ def make_filter(config: Dict, seed: int, r: int
     # every indexed key: the records and the wildcarded positives
     keys = np.unique(np.concatenate([rel.records, ids[labels > 0.5]]),
                      axis=0)
+    key_logits = filters.logits64(params, cols, keys)
+    tau = float(m["tau"])
+    served = {}
+    qm = quant.mode(config)
+    if qm is not None:
+        qparams = quant.quantize(params, qm)
+        deq = quant.dequantize(qparams, filters.param_shapes(cols, hidden),
+                               qm)
+        tau_q = quant.tau_q(params, deq, cols, qm, tau, sub_seed(seed, 6, r))
+        threshold = quant.threshold(tau_q)
+        # the fixup filter holds what either model rejects, so the
+        # guarantee does not lean on the calibration
+        key_logits = np.minimum(
+            key_logits, filters.logits64(deq, cols, keys) - threshold)
+        served = dict(qparams=qparams, tau_q=tau_q, threshold=threshold,
+                      dequantized=deq)
     bits, m_bits, n_hashes, n_keys = filters.build_fixup(
-        keys, filters.logits64(params, cols, keys), float(t["fixup_fpr"]),
-        int(t["fixup_capacity"]))
+        keys, key_logits, float(t["fixup_fpr"]), int(t["fixup_capacity"]))
     return rel, filters.Filter(
         theta=int(m["theta"]), ns=int(m["ns"]), cols=cols, hidden=hidden,
         params=params, bits=bits, m_bits=m_bits, n_hashes=n_hashes,
-        n_keys=n_keys, tau=float(m["tau"]))
+        n_keys=n_keys, tau=tau, **served)
 
 
 def make_pool(rel: relation.Relation, mix: Dict, seed: int, r: int
@@ -132,16 +150,24 @@ class Reference:
 
 def pool_models(filts: List[filters.Filter], pools: List[np.ndarray]
                 ) -> List[np.ndarray]:
-    """Per relation, the float64 reference logit of every pool row."""
-    return [filters.logits64(f.params, f.cols, rows)
+    """Per relation, the float64 reference logit of every pool row, on
+    the weights the program serves (a quantized filter's dequantized in
+    float32, as the program dequantizes them)."""
+    return [filters.logits64(f.served, f.cols, rows)
             for f, rows in zip(filts, pools)]
 
 
 def reference(filts: List[filters.Filter], pools: List[np.ndarray],
               is_record: List[np.ndarray], tenants: Tenants, sched,
-              logits: List[np.ndarray], block: int = 1 << 21) -> Reference:
+              logits: List[np.ndarray], block: int = 1 << 21,
+              thresholds: Optional[List[float]] = None) -> Reference:
     """The reference answers of every row of ``sched``'s requests, the
-    model side read from ``logits`` (per relation, per pool row)."""
+    model side read from ``logits`` (per relation, per pool row) against
+    each relation's served threshold (``thresholds``, logits: 0 for a
+    float32 filter at tau 0.5, ``logit(tau_q)`` for a quantized one);
+    ``BORDER_LOGIT`` is measured from that threshold."""
+    if thresholds is None:
+        thresholds = [f.threshold for f in filts]
     probes = [filters.probe(rows, f.m_bits, f.n_hashes)
               for f, rows in zip(filts, pools)]
     rows_n = np.asarray(sched.rows, np.int64)
@@ -165,7 +191,7 @@ def reference(filts: List[filters.Filter], pools: List[np.ndarray],
             i, ts = idx[sel], tenants.slot[t[sel]]
             got = tenants.bits[r][ts[:, None], words[i]] & masks[i]
             in_fixup = np.all(got != 0, axis=-1)
-            lg = logits[r][i]
+            lg = logits[r][i] - thresholds[r]
             answers[s + sel] = (lg >= 0) | in_fixup
             border[s + sel] = (np.abs(lg) < filters.BORDER_LOGIT) \
                 & ~in_fixup

@@ -27,14 +27,15 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from bench.lib.relation import WILDCARD
 
 # |logit| under which a float32 program and the float64 reference may
-# legitimately disagree on ``score >= tau`` (tau = 0.5 is logit 0). The
+# legitimately disagree on ``score >= tau`` (tau = 0.5 is logit 0; a
+# quantized filter's distance from its served threshold). The
 # fixup filter covers indexed keys up to this margin, and the
 # correctness check leaves rows within it (and not in the fixup filter)
 # out of the exact comparison. See PERF.md for the readings behind it.
@@ -128,18 +129,28 @@ def logits64(params, cols: List[Column], ids: np.ndarray,
              block: int = 65536) -> np.ndarray:
     """The plain reference: (n, n_cols) raw ids -> (n,) float64
     logits, ``relu(x @ w0 + b0) . w_out + b_out``."""
-    p = {g: {k: np.asarray(v, np.float64) for k, v in d.items()}
-         for g, d in params.items()}
-    n_sub = len(table_rows(cols))
+    p = _float64(params)
     out = np.empty(len(ids), np.float64)
     for s in range(0, len(ids), block):
-        enc = encode(ids[s:s + block], cols)
-        x = np.concatenate([p["embed"][f"col{i}"][enc[:, i]]
-                            for i in range(n_sub)], axis=-1)
-        h = np.maximum(x @ p["dense"]["w0"] + p["dense"]["b0"], 0.0)
-        out[s:s + block] = h @ p["dense"]["w_out"][:, 0] \
-            + p["dense"]["b_out"][0]
+        out[s:s + block] = _forward64(p, encode(ids[s:s + block], cols))
     return out
+
+
+def logits64_encoded(params, enc: np.ndarray) -> np.ndarray:
+    """:func:`logits64` of (n, n_subcolumns) encoded ids."""
+    return _forward64(_float64(params), enc)
+
+
+def _float64(params):
+    return {g: {k: np.asarray(v, np.float64) for k, v in d.items()}
+            for g, d in params.items()}
+
+
+def _forward64(p, enc: np.ndarray) -> np.ndarray:
+    x = np.concatenate([p["embed"][f"col{i}"][enc[:, i]]
+                        for i in range(enc.shape[1])], axis=-1)
+    h = np.maximum(x @ p["dense"]["w0"] + p["dense"]["b0"], 0.0)
+    return h @ p["dense"]["w_out"][:, 0] + p["dense"]["b_out"][0]
 
 
 def _jax_forward(params, enc, n_sub: int, precision: str):
@@ -296,7 +307,11 @@ def probe(ids: np.ndarray, m_bits: int, n_hashes: int
 class Filter:
     """One fitted filter: its plan (``theta``, ``ns``), float32 params,
     the fixup bitset and its geometry, and ``tau`` (a probability;
-    logit 0 at 0.5)."""
+    logit 0 at 0.5). A quantized configuration's filter also holds the
+    codes and scales the program is given (``qparams``), the threshold
+    it serves them at (``tau_q``, a probability; ``threshold``, the
+    logit the reference compares with) and the weights they dequantize
+    to in float32 (``dequantized``)."""
     theta: int
     ns: int
     cols: List[Column]
@@ -307,12 +322,23 @@ class Filter:
     n_hashes: int
     n_keys: int
     tau: float
+    qparams: Optional[Dict] = None
+    tau_q: Optional[float] = None
+    threshold: float = 0.0
+    dequantized: Optional[Dict] = None
+
+    @property
+    def served(self) -> Dict:
+        """The float32 weights the program serves."""
+        return self.params if self.dequantized is None else self.dequantized
 
 
 def build_fixup(keys: np.ndarray, key_logits: np.ndarray, fpr: float,
                 capacity: int) -> Tuple[np.ndarray, int, int, int]:
-    """Bitset holding every key with logit below ``+BORDER_LOGIT``,
-    sized for ``capacity`` keys at ``fpr`` whatever the count, so that
+    """Bitset holding every key with logit below ``+BORDER_LOGIT`` (for
+    a quantized filter, the lower of its float32 logit and its
+    quantized logit's distance above ``threshold``), sized for
+    ``capacity`` keys at ``fpr`` whatever the count, so that
     every seed's filter has the same size (more keys than that are
     still held, at a higher false-positive rate): ``(bits, m_bits,
     n_hashes, n_inserted)``."""

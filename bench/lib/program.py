@@ -10,15 +10,18 @@ program's defaults.
 """
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench.lib import filters
+from bench.lib import filters, quant
 
 
-def save_filter(directory: str, filt: filters.Filter) -> None:
-    """Write ``filt`` as an ``existence_index_v2`` checkpoint."""
+def save_filter(directory: str, filt: filters.Filter,
+                qm: Optional[Dict] = None) -> None:
+    """Write ``filt`` as an ``existence_index_v2`` checkpoint, or, under
+    the quantization ``qm`` (``quant.mode``), as ``existence_index_v3``
+    holding the benchmark's own codes, scales and ``tau_q``."""
     from repro.core import bloom, compression, existence, fixup, lmbf
     cplan = compression.make_plan([c.v for c in filt.cols],
                                   theta=filt.theta, ns=filt.ns)
@@ -30,19 +33,32 @@ def save_filter(directory: str, filt: filters.Filter) -> None:
                                      n_hashes=filt.n_hashes),
             bits=filt.bits, n_false_negatives=filt.n_keys),
         tau=filt.tau, train_log={"made_by": "bench"})
-    existence.save_index(directory, idx, step=0)
+    if qm is None:
+        existence.save_index(directory, idx, step=0)
+        return
+    # the program writes the quantized state it holds for this mode, so
+    # hand it ours; had it made its own, the check would test nothing
+    idx.quant_cache = {"meta": dict(qm), "qparams": filt.qparams,
+                       "tau": filt.tau_q, "pinned": False}
+    existence.save_index(directory, idx, step=0, quant=qm)
+    if idx.quant_cache["qparams"] is not filt.qparams:
+        raise RuntimeError("the program quantized the filter itself: its "
+                           f"mode {idx.quant_cache['meta']} is not {qm}")
 
 
 def serve_config(config: Dict, *, trace: bool):
     """The ``ServeConfig`` the configuration pins: grouping on, float32
-    arenas; everything else at the program's defaults. ``trace``
-    attaches the program's span tracer (per-layer metrics only)."""
+    or quantized arenas (``serving.dtype``, ``serving.quant``);
+    everything else at the program's defaults. ``trace`` attaches the
+    program's span tracer (per-layer metrics only)."""
     from repro.serve_filter import ServeConfig
     from repro.serve_filter.config import GroupingConfig, MetricsConfig
-    serving = config["serving"]
-    if serving["dtype"] != "float32" or not serving["grouped"]:
-        raise ValueError("this harness serves grouped float32 arenas")
-    return ServeConfig(grouping=GroupingConfig(enabled=True),
+    from repro.serve_filter.plan import QuantConfig
+    if not config["serving"]["grouped"]:
+        raise ValueError("this harness serves grouped arenas")
+    qm = quant.mode(config)
+    q = QuantConfig() if qm is None else QuantConfig(enabled=True, **qm)
+    return ServeConfig(grouping=GroupingConfig(enabled=True), quant=q,
                        metrics=MetricsConfig(trace=trace))
 
 

@@ -6,6 +6,14 @@ future resolved in that step. An open-loop request is timed from when
 it was due; a closed-loop client sends its next request as soon as the
 last one resolved.
 
+The futures still out are kept in one FIFO per tenant
+(:class:`Outstanding`), and a pass polls each tenant's queue only up to
+its first future that has not resolved. That is exact because the
+server resolves a tenant's requests in the order they were submitted
+(its queue is FIFO and batches retire in dispatch order), and it keeps
+a pass's cost to the futures that resolved plus the tenants with work
+out, so a backlog does not slow the loop that has to drain it.
+
 With ``annotate`` the loop's own calls are wrapped in
 ``jax.profiler.TraceAnnotation`` (``bench.submit``, ``bench.step``,
 ``bench.poll``, ``bench.wait``), so a device trace can say what the
@@ -14,6 +22,7 @@ host was doing in each idle gap.
 from __future__ import annotations
 
 import bisect
+import collections
 import dataclasses
 import time
 from typing import List, Optional
@@ -23,6 +32,7 @@ import numpy as np
 from bench.lib.traffic import Schedule
 
 clock = time.perf_counter
+sleep = time.sleep
 
 
 class _Null:
@@ -97,6 +107,55 @@ class _Items:
         return out
 
 
+class Outstanding:
+    """The futures not yet resolved, in one FIFO per tenant, in the
+    order they were submitted."""
+
+    def __init__(self):
+        self._queues = {}
+        self._n = 0
+
+    def __len__(self) -> int:
+        return self._n
+
+    def add(self, futures, ks, tenants) -> None:
+        """Request ``ks[j]`` of tenant ``tenants[j]`` went out as
+        ``futures[j]``."""
+        queues = self._queues
+        for f, k, t in zip(futures, ks, tenants):
+            q = queues.get(t)
+            if q is None:
+                q = queues[t] = collections.deque()
+            q.append((f, k))
+        self._n += len(futures)
+
+    def poll(self, on_done, sweep: bool = False) -> int:
+        """``on_done(future, k)`` for each resolved future, in each
+        tenant's order, up to the tenant's first unresolved one; with
+        ``sweep``, every resolved future. Returns how many resolved."""
+        got = 0
+        for t in list(self._queues):
+            q = self._queues[t]
+            if sweep:
+                still = collections.deque()
+                for f, k in q:
+                    if f.done():
+                        on_done(f, k)
+                        got += 1
+                    else:
+                        still.append((f, k))
+                q = self._queues[t] = still
+            else:
+                while q and q[0][0].done():
+                    f, k = q.popleft()
+                    on_done(f, k)
+                    got += 1
+            if not q:
+                del self._queues[t]
+        self._n -= got
+        return got
+
+
 def run_open(server, sched: Schedule, tenants, pools, relation_of, *,
              seconds: float, annotate: bool, drain_s: float) -> Result:
     Ann = _annotation(annotate)
@@ -108,7 +167,17 @@ def run_open(server, sched: Schedule, tenants, pools, relation_of, *,
     failed = np.zeros(n, bool)
     late = np.full(n, np.nan)
     due_rel = sched.due.tolist()
-    outstanding = []
+    outstanding = Outstanding()
+    t = 0.0
+
+    def on_done(f, k):
+        t_done[k] = t
+        req = f.request
+        if req.error is None:
+            answers[start[k]:start[k] + req.ids.shape[0]] = req.answers
+        else:
+            failed[k] = True
+
     i = 0
     t0 = clock()
     due_abs = t0 + sched.due
@@ -120,27 +189,18 @@ def run_open(server, sched: Schedule, tenants, pools, relation_of, *,
             with Ann("bench.submit"):
                 futs = server.submit_many(items[i:j])
             late[i:j] = clock() - due_abs[i:j]
-            outstanding.extend(zip(futs, range(i, j)))
+            outstanding.add(futs, range(i, j), items.tenant[i:j])
             i = j
         if outstanding:
             with Ann("bench.step"):
                 server.step()
             with Ann("bench.poll"):
                 t = clock()
-                still = []
-                for f, k in outstanding:
-                    if f.done():
-                        t_done[k] = t
-                        req = f.request
-                        if req.error is None:
-                            answers[start[k]:start[k] + req.ids.shape[0]] \
-                                = req.answers
-                        else:
-                            failed[k] = True
-                    else:
-                        still.append((f, k))
-                outstanding = still
+                outstanding.poll(on_done)
             if t > give_up:
+                # nothing resolved may go unseen behind a head that
+                # never did
+                outstanding.poll(on_done, sweep=True)
                 break
         elif i >= n:
             break
@@ -148,7 +208,7 @@ def run_open(server, sched: Schedule, tenants, pools, relation_of, *,
             with Ann("bench.wait"):
                 gap = due_abs[i] - clock()
                 if gap > 2e-3:
-                    time.sleep(gap - 1e-3)
+                    sleep(gap - 1e-3)
                 while clock() < due_abs[i]:
                     pass
     t_end = max(t0 + seconds, float(np.nanmax(t_done)) if n else t0)
@@ -171,45 +231,44 @@ def run_closed(server, sched: Schedule, tenants, pools, relation_of, *,
     items = _Items(sched, tenants, pools, relation_of)
     n = len(items)
     packed, t_done, failed = [], [], []
+    outstanding = Outstanding()
+    t = 0.0
+
+    def on_done(f, k):
+        t_done[k] = t
+        req = f.request
+        if req.error is None:
+            packed[k] = np.packbits(req.answers)
+        else:
+            failed[k] = True
+
+    def send(i, m):
+        with Ann("bench.submit"):
+            futs = server.submit_many(items.cycle(i, m))
+        outstanding.add(futs, range(i, i + m),
+                        [items.tenant[k % n] for k in range(i, i + m)])
+        packed.extend([None] * m)
+        t_done.extend([np.nan] * m)
+        failed.extend([False] * m)
+
     t0 = clock()
     t_close = t0 + seconds
     t_end = None
-    with Ann("bench.submit"):
-        futs = server.submit_many(items.cycle(0, clients))
-    outstanding = list(zip(futs, range(clients)))
-    packed += [None] * clients
-    t_done += [np.nan] * clients
-    failed += [False] * clients
+    send(0, clients)
     i = clients
     while outstanding:
         with Ann("bench.step"):
             server.step()
         with Ann("bench.poll"):
             t = clock()
-            still, freed = [], 0
-            for f, k in outstanding:
-                if f.done():
-                    t_done[k] = t
-                    req = f.request
-                    if req.error is None:
-                        packed[k] = np.packbits(req.answers)
-                    else:
-                        failed[k] = True
-                    freed += 1
-                else:
-                    still.append((f, k))
-            outstanding = still
+            freed = outstanding.poll(on_done)
         if t_end is None and t >= t_close:
             t_end = t
         if t_end is None and freed:
-            with Ann("bench.submit"):
-                futs = server.submit_many(items.cycle(i, freed))
-            outstanding.extend(zip(futs, range(i, i + freed)))
-            packed += [None] * freed
-            t_done += [np.nan] * freed
-            failed += [False] * freed
+            send(i, freed)
             i += freed
         if t > t_close + drain_s:
+            outstanding.poll(on_done, sweep=True)
             break
     if t_end is None:
         t_end = clock()

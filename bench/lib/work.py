@@ -7,9 +7,12 @@ read the same whatever program implements it:
   output dot (``2 * hidden``). Bias adds, ReLU, the sigmoid, the
   division of the ids and the hashing are left out.
 * Bytes per row, a lower bound: the row's raw ids (``4 * n_cols``), its
-  embedding rows (``4 * concat_dim``), the fixup bitset words it probes
+  embedding rows (``4 * concat_dim`` in float32; 1 byte a value in
+  int8 and half a byte in int4, plus one 4-byte scale for each of the
+  row's subcolumn tables), the fixup bitset words it probes
   (``4 * n_hashes``) and its three answer bytes. The MLP weights are
-  left out: a tenant's weights may be read once for many rows.
+  left out: a tenant's weights may be read once for many rows. The
+  dequantizing multiply is left out of the FLOPs, as bias adds are.
 
 A roofline share is ``max(flops / peak_flops, bytes / peak_bytes)``
 over the measured time, so a lower bound of the work never reads above
@@ -42,6 +45,10 @@ def program_s(trace: Dict) -> float:
     return sum(trace["module_s"].get(m, 0.0) for m in PROGRAM_MODULES)
 
 
+# bytes of one embedding value as served
+VALUE_BYTES = {"float32": 4, "int8": 1, "int4-nf4": 0.5}
+
+
 def per_row(config: Dict) -> Dict[str, float]:
     """``{"flops", "bytes"}`` one answered row needs under ``config``."""
     m = config["model"]
@@ -51,5 +58,9 @@ def per_row(config: Dict) -> Dict[str, float]:
     _, n_hashes = filters.bloom_size(
         int(config["train"]["fixup_capacity"]), config["train"]["fixup_fpr"])
     n_cols = len(config["relation"]["cards"])
+    value_bytes = VALUE_BYTES[config["serving"]["dtype"]]
+    embed = value_bytes * d
+    if value_bytes < 4:
+        embed += 4 * len(filters.table_rows(cols))
     return {"flops": float(2 * d * h + 2 * h),
-            "bytes": float(4 * n_cols + 4 * d + 4 * n_hashes + 3)}
+            "bytes": float(4 * n_cols + embed + 4 * n_hashes + 3)}

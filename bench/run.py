@@ -112,7 +112,7 @@ def set_up(cfg, mix, args, timings, jax):
     """Filters, pools, tenants and their checkpoints, the admitted and
     warmed server."""
     from types import SimpleNamespace
-    from bench.lib import cell, program
+    from bench.lib import cell, program, quant
     from repro.serve_filter import FilterServer
     t = time.perf_counter()
     made = [cell.make_filter(cfg, args.seed, r)
@@ -121,7 +121,9 @@ def set_up(cfg, mix, args, timings, jax):
     timings["fit_s"] = time.perf_counter() - t
     for r, f in enumerate(filts):
         log(f"relation {r}: fixup keys {f.n_keys} m_bits {f.m_bits} "
-            f"n_hashes {f.n_hashes}")
+            f"n_hashes {f.n_hashes}"
+            + ("" if f.tau_q is None else f" tau_q {f.tau_q!r} threshold "
+               f"logit {f.threshold!r}"))
 
     t = time.perf_counter()
     pools, is_record = map(list, zip(*[cell.make_pool(rel, mix, args.seed, r)
@@ -132,9 +134,10 @@ def set_up(cfg, mix, args, timings, jax):
     t = time.perf_counter()
     ckpt = tempfile.mkdtemp(prefix="bench-ckpt-")
     names = cell.tenant_names(cfg)
+    qm = quant.mode(cfg)
     for i, name in enumerate(names):
         program.save_filter(os.path.join(ckpt, name), tenants.filter_of(
-            i, filts[tenants.relation[i]]))
+            i, filts[tenants.relation[i]]), qm)
     timings["ckpt_s"] = time.perf_counter() - t
 
     t = time.perf_counter()

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from bench import run
-from bench.lib import spec, traffic
+from bench.lib import filters, program, spec, traffic, work
 
 ROOT = run.ROOT
 BENCH = run.BENCH
@@ -134,6 +134,22 @@ def test_new_entries_need_no_edit(tmp_path, bench):
                            "layer": "scheduler (serve_filter/scheduler.py)",
                            "moves": "p90_ms",
                            "workloads": ["airplane-t5500.fleet-burst"]})
+    # a configuration served from int4-NF4 arenas, with its own
+    # fleet-open rate
+    qname = "airplane-t5500-int4nf4"
+    qcfg = dict(spec.config(bench, "airplane-t5500", ROOT), name=qname)
+    qcfg["serving"] = dict(qcfg["serving"], dtype="int4-nf4", quant={
+        "row_group": 32, "calib_samples": 512, "margin_safety": 2.0,
+        "margin_floor": 1e-3})
+    with open(nb / "configs" / f"{qname}.json", "w") as f:
+        json.dump(qcfg, f)
+    with open(nb / "traffic" / "fleet-open" / f"{qname}.json", "w") as f:
+        json.dump({"rate_rows_per_s": 300000}, f)
+    b["configs"].append(dict(b["configs"][0], name=qname,
+                             file=f"bench/configs/{qname}.json"))
+    b["workloads"].append({"name": f"{qname}.fleet-open", "config": qname,
+                           "traffic": "fleet-open", "chips": 1,
+                           "why": "int4-NF4 arenas"})
     assert spec.validate(b) == []
     mix = traffic.load(str(nb), "fleet-burst", "airplane-t5500")
     assert mix["rate_rows_per_s"] == 1000.0
@@ -141,6 +157,70 @@ def test_new_entries_need_no_edit(tmp_path, bench):
         b, "airplane-t5500.fleet-burst", True)]
     assert names == ["queue_wait_p99_ms.burst"]
     assert spec.reader(names[0], str(nb))({}) == 1.0
+    cell = spec.workload(b, f"{qname}.fleet-open")
+    cfg = spec.config(b, cell["config"], str(tmp_path))
+    assert cfg == qcfg
+    assert traffic.load(str(nb), "fleet-open", qname)[
+        "rate_rows_per_s"] == 300000
+    q = program.serve_config(cfg, trace=False).quant
+    assert (q.enabled, q.bits, q.grid, q.row_group) == (True, 4, "nf4", 32)
+    assert work.per_row(cfg)["bytes"] == 125.5
+
+
+def test_quantized_configuration_states_its_quant_block(bench):
+    cfg = spec.config(bench, "airplane-t5500", ROOT)
+    cfg["serving"] = dict(cfg["serving"], dtype="int8")
+    with pytest.raises(ValueError, match="states serving.quant"):
+        program.serve_config(cfg, trace=False)
+    cfg["serving"]["dtype"] = "bfloat16"
+    with pytest.raises(ValueError, match="none of float32"):
+        program.serve_config(cfg, trace=False)
+    cfg["serving"] = dict(cfg["serving"], dtype="float32", grouped=False)
+    with pytest.raises(ValueError, match="grouped"):
+        program.serve_config(cfg, trace=False)
+
+
+# the checkpoint of a fixed small float32 filter as the harness wrote it
+# before quantized configurations: each array's CRC-32, and the CRC-32
+# of the index's meta (key-sorted JSON)
+PARENT_CKPT_CRC = {
+    "['fixup_bits']": 307507916,
+    "['params']['dense']['b0']": 1538376245,
+    "['params']['dense']['b_out']": 3384322503,
+    "['params']['dense']['w0']": 3870195317,
+    "['params']['dense']['w_out']": 610781158,
+    "['params']['embed']['col0']": 2796057447,
+    "['params']['embed']['col1']": 3610764301,
+    "['params']['embed']['col2']": 2786897978,
+    "['params']['embed']['col3']": 2805701297,
+    "['params']['embed']['col4']": 1219548011,
+    "['params']['embed']['col5']": 2601817352,
+    "['params']['embed']['col6']": 4200174231,
+    "['params']['embed']['col7']": 3484168991,
+    "['params']['embed']['col8']": 2488565066,
+    "['params']['embed']['col9']": 129008568,
+}
+PARENT_META_CRC = 956113159
+
+
+def test_float32_checkpoint_is_unchanged(tmp_path):
+    import zlib
+    cols = filters.plan([50, 60, 40, 30, 20, 25, 10], 30, 2)
+    rng = np.random.default_rng(20261019)
+    params = {g: {k: rng.standard_normal(s).astype(np.float32)
+                  for k, s in d.items()}
+              for g, d in filters.param_shapes(cols, 8).items()}
+    m_bits, n_hashes = filters.bloom_size(100, 0.01)
+    bits = rng.integers(0, 2 ** 32, (m_bits + 31) // 32, dtype=np.uint32)
+    program.save_filter(str(tmp_path), filters.Filter(
+        theta=30, ns=2, cols=cols, hidden=8, params=params, bits=bits,
+        m_bits=m_bits, n_hashes=n_hashes, n_keys=17, tau=0.5))
+    with open(tmp_path / "step_0" / "meta.json") as f:
+        meta = json.load(f)
+    assert {k: v["crc32"] for k, v in meta["arrays"].items()} \
+        == PARENT_CKPT_CRC
+    assert zlib.crc32(json.dumps(meta["extra"], sort_keys=True).encode()) \
+        == PARENT_META_CRC
 
 
 def _run(cwd, env_extra):

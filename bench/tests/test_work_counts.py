@@ -7,6 +7,11 @@ wide): concat_dim 8*3 + 7 + 8 + 6 = 45, input dim 9933 (Table 1).
 FLOPs 2*45*64 + 2*64 = 5888; bytes 4*7 ids + 4*45 embedding + 4*7
 probe words (7 hashes at FPR 0.01) + 3 answers = 239.
 
+airplane-t5500 served from int4-NF4 arenas: the same 45 embedding
+values at half a byte (22.5), plus one 4-byte scale for each of the 11
+subcolumn tables a row reads (44): 4*7 + 22.5 + 44 + 4*7 + 3 = 125.5
+bytes; in int8, 45 + 44 + 28 + 28 + 3 = 148. The FLOPs do not change.
+
 dmv-t100: ten columns above 100 split (widths 3+3, 2+2, 2+2, 1+1, 2+2,
 2+2, 2+2, 2+2, 2+2, 1+1 = 38), nine stay whole (5, 27, 27, 64, 40, 8,
 3, 3, 2 rows: 1+2+2+2+2+1+1+1+1 = 13): concat_dim 51, input dim 892.
@@ -38,6 +43,14 @@ def test_counts_by_hand(name, concat, input_dim, flops, nbytes):
     assert filters.concat_dim(cols) == concat
     assert sum(filters.table_rows(cols)) == input_dim
     assert work.per_row(cfg) == {"flops": flops, "bytes": nbytes}
+
+
+@pytest.mark.parametrize("dtype,nbytes", [("int4-nf4", 125.5),
+                                          ("int8", 148.0)])
+def test_quantized_counts_by_hand(dtype, nbytes):
+    cfg = _config("airplane-t5500")
+    cfg["serving"] = dict(cfg["serving"], dtype=dtype)
+    assert work.per_row(cfg) == {"flops": 5888.0, "bytes": nbytes}
 
 
 def test_airplane_split_by_hand():

@@ -8,9 +8,15 @@ from bench.lib import spec, traffic
 SEED = 2 ** 31 + 4242
 
 
-def config(name="airplane-t5500", tenants=8):
+# serving.quant at the program's defaults, but for row groups of 4 rows,
+# so that the small tables hold several
+QUANT = {"row_group": 4, "calib_samples": 512, "margin_safety": 2.0,
+         "margin_floor": 1e-3}
+
+
+def config(name="airplane-t5500", tenants=8, dtype="float32"):
     """The configuration with its relation cut to a few thousand
-    records over small columns and a short fit."""
+    records over small columns and a short fit, served as ``dtype``."""
     cfg = copy.deepcopy(spec.config(spec.load(run.ROOT), name, run.ROOT))
     cfg["relation"] = dict(cfg["relation"], cards=[50, 60, 40, 30, 20, 25,
                                                    10], records=2000)
@@ -18,6 +24,8 @@ def config(name="airplane-t5500", tenants=8):
     cfg["train"] = dict(cfg["train"], steps=50, n_pos=2000, n_neg=2000,
                         fixup_capacity=2000)
     cfg["serving"] = dict(cfg["serving"], tenants=tenants, tenant_records=16)
+    if dtype != "float32":
+        cfg["serving"].update(dtype=dtype, quant=dict(QUANT))
     return cfg
 
 
