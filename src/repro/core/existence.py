@@ -28,6 +28,7 @@ from repro.core import bloom, compression as comp, fixup, lmbf, memory
 from repro.data import tuples as tuples_lib
 from repro.nn import abstract_params
 from repro.optim import Adam
+from repro.runtime.trace import NULL_TRACER, Tracer
 
 
 @dataclasses.dataclass
@@ -332,9 +333,36 @@ def save_index(directory: str, idx: ExistenceIndex, *, step: int = 0,
     ckpt.save(directory, step, tree, extra=meta, keep=keep)
 
 
-def load_index(directory: str, step: Optional[int] = None) -> ExistenceIndex:
+def load_index(directory: str, step: Optional[int] = None, *,
+               tracer: Tracer = NULL_TRACER) -> ExistenceIndex:
     """Rebuild a fitted :class:`ExistenceIndex` written by
-    :func:`save_index`."""
+    :func:`save_index`. ``tracer`` records two ``detail`` spans:
+    ``restore`` (the checkpoint read, its CRC checks and the arrays'
+    restore) and, for a v3 checkpoint, ``quant_state`` (binding its
+    codes, scales and ``tau_q`` as the index's quant cache)."""
+    with tracer.span("restore", cat="detail"):
+        idx, meta, tree = _restore_index(directory, step)
+    if meta["kind"] == "existence_index_v3":
+        with tracer.span("quant_state", cat="detail"):
+            qmeta = meta["quant"]
+            idx.quant_cache = {
+                "meta": {"bits": int(qmeta["bits"]),
+                         "grid": str(qmeta["grid"]),
+                         "row_group": int(qmeta["row_group"]),
+                         "calib_samples": int(qmeta["calib_samples"]),
+                         "margin_safety": float(qmeta["margin_safety"]),
+                         "margin_floor": float(qmeta["margin_floor"])},
+                "qparams": jax.tree_util.tree_map(np.asarray,
+                                                  tree["quant"]),
+                "tau": float(qmeta["tau_q"]),
+                "pinned": True,
+            }
+    return idx
+
+
+def _restore_index(directory: str, step: Optional[int]):
+    """The index of a checkpoint with no quant cache bound yet, its
+    meta and the restored tree."""
     if step is None:
         step = ckpt.latest_step(directory)
         if step is None:
@@ -349,7 +377,7 @@ def load_index(directory: str, step: Optional[int] = None) -> ExistenceIndex:
             "head (existence_index_v1); its scores differ in the last "
             "ulps under the current head, so rows borderline at tau may "
             "flip and the no-false-negative guarantee is not assured — "
-            "refit and re-save to upgrade", UserWarning, stacklevel=2)
+            "refit and re-save to upgrade", UserWarning, stacklevel=3)
     cfg = config_from_meta(meta)
     bp = bloom.BloomParams(m_bits=int(meta["fixup"]["m_bits"]),
                            n_hashes=int(meta["fixup"]["n_hashes"]))
@@ -364,22 +392,8 @@ def load_index(directory: str, step: Optional[int] = None) -> ExistenceIndex:
         params=bp, bits=np.asarray(tree["fixup_bits"]),
         n_false_negatives=int(meta["fixup"]["n_false_negatives"]))
     idx = ExistenceIndex(cfg=cfg, params=tree["params"], fixup_filter=fx,
-                         tau=float(meta["tau"]),
-                         train_log=meta["train_log"])
-    if meta["kind"] == "existence_index_v3":
-        qmeta = {k: v for k, v in meta["quant"].items() if k != "tau_q"}
-        idx.quant_cache = {
-            "meta": {"bits": int(qmeta["bits"]),
-                     "grid": str(qmeta["grid"]),
-                     "row_group": int(qmeta["row_group"]),
-                     "calib_samples": int(qmeta["calib_samples"]),
-                     "margin_safety": float(qmeta["margin_safety"]),
-                     "margin_floor": float(qmeta["margin_floor"])},
-            "qparams": jax.tree_util.tree_map(np.asarray, tree["quant"]),
-            "tau": float(meta["quant"]["tau_q"]),
-            "pinned": True,
-        }
-    return idx
+                         tau=float(meta["tau"]), train_log=meta["train_log"])
+    return idx, meta, tree
 
 
 def load_fixup_only(directory: str, step: Optional[int] = None

@@ -47,6 +47,7 @@ each shard only ever receives its own slice.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -61,6 +62,17 @@ from repro.serve_filter.plan import GroupKey, quantize_index
 
 MIN_CAPACITY = 4
 _BITS_GROWTH = 1.5
+
+
+def _logit(p: float) -> float:
+    return math.log(p) - math.log1p(-p)
+
+
+def _tau_margin(tau: float, tau_q: float) -> float:
+    """``logit(tau) - logit(tau_q)``, with ``tau`` clamped as
+    ``lmbf.calibrated_tau`` clamps it before lowering it to ``tau_q``
+    (so a tenant at tau 0 or 1 reads its calibrated margin)."""
+    return _logit(min(max(tau, 1e-6), 1.0 - 1e-6)) - _logit(tau_q)
 
 
 class PlanGroupArena:
@@ -149,6 +161,9 @@ class PlanGroupArena:
         self._tile_cache: Dict[bytes, object] = {}
         self.tile_hits = 0                  # dispatches the cache spared
         self.tile_misses = 0                # dispatches that gathered
+        # per quantized tenant, logit(tau) - logit(tau_q): how far the
+        # calibration lowered its threshold, set at add and swap
+        self._tau_margin: Dict[str, float] = {}
 
     # ------------------------------------------------------------- access
     def __len__(self) -> int:
@@ -236,6 +251,12 @@ class PlanGroupArena:
         """Live tenants / slot capacity in [0, 1] (0.0 when empty)."""
         return len(self._slots) / self.capacity if self.capacity else 0.0
 
+    def tau_margins(self) -> Dict[str, float]:
+        """Per live tenant of a quantized arena, how far its served
+        threshold sits below its float32 one, in logits:
+        ``logit(tau) - logit(tau_q)`` (empty on a float32 arena)."""
+        return dict(self._tau_margin)
+
     def health(self) -> Dict[str, float]:
         """Gauge snapshot for the stats surface: occupancy, churn debt,
         lifetime compaction/growth counts, and footprints."""
@@ -271,7 +292,7 @@ class PlanGroupArena:
             prefix += ng
         return starts
 
-    def _write_slot(self, slot: int,
+    def _write_slot(self, tenant: str, slot: int,
                     index: existence.ExistenceIndex) -> None:
         """Write a fitted index's payload into an OWNED slot whose
         bitset word range is already allocated (``word_base`` /
@@ -282,7 +303,8 @@ class PlanGroupArena:
         admit/reload — and stores the tenant's calibrated threshold in
         the tau vector, so quantized slots keep the no-false-negative
         invariant and reload stays zero-drain (the mirrors mutate, but
-        in-flight batches hold the previous device snapshots)."""
+        in-flight batches hold the previous device snapshots), and
+        records how far the calibration lowered it (``tau_margins``)."""
         if self._quant:
             # the shared quantize entry point: cached on the index, so a
             # v3-checkpoint hydration (or a second placement of the same
@@ -303,6 +325,7 @@ class PlanGroupArena:
                                   sstart + (slot + 1) * ng] = \
                     qp["embed_scale"][f"col{i}"]
             self._tau[slot] = np.float32(tau)
+            self._tau_margin[tenant] = _tau_margin(index.tau, tau)
         else:
             for name, arr in index.params["dense"].items():
                 self._params["dense"][name][slot] = np.asarray(arr)
@@ -328,7 +351,7 @@ class PlanGroupArena:
         fp = index.fixup_filter.params
         self._word_base[slot] = self._alloc_words(fp.n_words)
         self._word_len[slot] = fp.n_words
-        self._write_slot(slot, index)
+        self._write_slot(tenant, slot, index)
         self._slots[tenant] = slot
         self._touch()
         return slot
@@ -363,7 +386,7 @@ class PlanGroupArena:
                 self._free_ranges.append((base, length))
             self._word_base[slot] = new_base
             self._word_len[slot] = fp.n_words
-        self._write_slot(slot, index)
+        self._write_slot(tenant, slot, index)
         self._touch()
         return slot
 
@@ -371,6 +394,7 @@ class PlanGroupArena:
         slot = self._slots.pop(tenant, None)
         if slot is None:
             return
+        self._tau_margin.pop(tenant, None)
         self._free.append(slot)
         base, length = int(self._word_base[slot]), int(self._word_len[slot])
         if length:

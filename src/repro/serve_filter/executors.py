@@ -67,6 +67,7 @@ and no misanswered in-flight rows, on every placement.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Dict, Optional, Tuple
@@ -547,8 +548,9 @@ def _grouped_program(key: GroupKey, mesh: Optional[Mesh]):
             if quant:
                 s = params["dense_scale"][f"w{li}"] \
                     .at[tile_idx].get(mode="promise_in_bounds")
-                w = deq4(w, s, dense_dims[f"w{li}"]) if bits4 \
-                    else w.astype(cfg.dtype) * s[:, None, :]
+                with jax.named_scope("dequant_dense"):
+                    w = deq4(w, s, dense_dims[f"w{li}"]) if bits4 \
+                        else w.astype(cfg.dtype) * s[:, None, :]
             tiles[f"w{li}"] = w
             tiles[f"b{li}"] = params["dense"][f"b{li}"] \
                 .at[tile_idx].get(mode="promise_in_bounds")
@@ -557,10 +559,11 @@ def _grouped_program(key: GroupKey, mesh: Optional[Mesh]):
         if quant:
             s = params["dense_scale"]["w_out"] \
                 .at[tile_idx].get(mode="promise_in_bounds")  # (g, 1)
-            if bits4:
-                w_out = deq4(w_out, s, dense_dims["w_out"])[..., 0]
-            else:
-                w_out = w_out[..., 0].astype(cfg.dtype) * s
+            with jax.named_scope("dequant_dense"):
+                if bits4:
+                    w_out = deq4(w_out, s, dense_dims["w_out"])[..., 0]
+                else:
+                    w_out = w_out[..., 0].astype(cfg.dtype) * s
         else:
             w_out = w_out[..., 0]
         tiles["w_out"] = w_out
@@ -634,15 +637,17 @@ def _grouped_program(key: GroupKey, mesh: Optional[Mesh]):
                     # every placement (at bits=4 the gathered packed
                     # bytes double to 2*pk code columns here — the
                     # per-column e-slice below trims the pad)
-                    sc = p["embed_scale"].at[sidx.reshape(-1)] \
-                        .get(mode="promise_in_bounds").reshape(shape)
-                    if bits4:
-                        g = lmbf.nibble_values(
-                            lmbf.unpack_nibbles(g, axis=-1), qgrid,
-                            cfg_.dtype)
-                    else:
-                        g = g.astype(cfg_.dtype)
-                    return g * sc[..., None]
+                    with jax.named_scope("q_gather"):
+                        sc = p["embed_scale"].at[sidx.reshape(-1)] \
+                            .get(mode="promise_in_bounds").reshape(shape)
+                    with jax.named_scope("dequant"):
+                        if bits4:
+                            g = lmbf.nibble_values(
+                                lmbf.unpack_nibbles(g, axis=-1), qgrid,
+                                cfg_.dtype)
+                        else:
+                            g = g.astype(cfg_.dtype)
+                        return g * sc[..., None]
 
                 if sharded:
                     # row-sharded combined matrix: every global row is
@@ -674,9 +679,13 @@ def _grouped_program(key: GroupKey, mesh: Optional[Mesh]):
                             idx, sidx, flat, p["embed_scale"],
                             block_n=key.block_n, interpret=key.interpret)
                 else:
-                    gathered = flat.at[idx.reshape(-1)] \
-                        .get(mode="promise_in_bounds") \
-                        .reshape(idx.shape[0], len(emb_cols), -1)
+                    # a quantized arena's packed (or int8) row gather
+                    # runs under its own scope, then its dequant
+                    with (jax.named_scope("q_gather") if quant
+                          else contextlib.nullcontext()):
+                        gathered = flat.at[idx.reshape(-1)] \
+                            .get(mode="promise_in_bounds") \
+                            .reshape(idx.shape[0], len(emb_cols), -1)
                     if quant:
                         gathered = dequant(
                             gathered, idx.shape[:1] + (len(emb_cols),))

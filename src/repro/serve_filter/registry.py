@@ -256,6 +256,33 @@ class FilterRegistry:
     def total_mb(self) -> float:
         return sum(e.total_mb for e in self._entries.values())
 
+    def tenant_device_bytes(self) -> int:
+        """Device bytes the live entries hold outside their arenas:
+        each index's params, fixup bits and quant cache, an ungrouped
+        entry's placed arrays, a degraded entry's backup bits — where
+        they are JAX arrays. Each array counts as the device stores
+        it (``on_device_size_in_bytes``, so a TPU's tiled padding of
+        the many small per-tenant arrays is included), once if several
+        entries share it, and a sharded one by the slice one device
+        holds. Walks every entry: a snapshot-time reading, never a
+        per-batch one."""
+        seen, total = set(), 0
+        for e in self._entries.values():
+            trees = []
+            if e.placed is not None:
+                trees += [e.placed.params, e.placed.bits]
+            if e.index is not None:
+                trees += [e.index.params, e.index.fixup_filter.bits,
+                          (e.index.quant_cache or {}).get("qparams")]
+            if e.backup_only is not None:
+                trees.append(e.backup_only.bits)
+            for leaf in jax.tree.leaves(trees):
+                if isinstance(leaf, jax.Array) and id(leaf) not in seen:
+                    seen.add(id(leaf))
+                    total += leaf.on_device_size_in_bytes() // \
+                        len(leaf.sharding.device_set)
+        return total
+
     def get(self, tenant: str) -> FilterEntry:
         """Fetch + touch (bumps LRU recency)."""
         entry = self._entries[tenant]
@@ -430,7 +457,8 @@ class FilterRegistry:
         if index is None:
             self.injector.check("checkpoint_read", tenant)
             index = existence.load_index(
-                os.path.join(spec.checkpoint, tenant), step=spec.step)
+                os.path.join(spec.checkpoint, tenant), step=spec.step,
+                tracer=self.tracer)
         self.injector.check("hydrate", tenant)
         return self._install(tenant, index, prev, pinned=spec.pinned,
                              groupable=spec.groupable)

@@ -431,6 +431,18 @@ class FilterServer:
             if a.key.quant.enabled) / 2 ** 20
         arena_gb = snap["arena_mb"] / 1024.0
         snap["tenants_per_gb"] = (live / arena_gb) if arena_gb else 0.0
+        # the device arrays the entries keep outside the arenas (the
+        # float32 params an index brings, ungrouped placements), as
+        # the device stores them, tiled padding included
+        snap["tenant_device_mb"] = \
+            self.registry.tenant_device_bytes() / 2 ** 20
+        # how far calibration lowered the quantized tenants' thresholds,
+        # logit(tau) - logit(tau_q) (0.0 with no quantized tenant)
+        margins = [m for a in arenas for m in a.tau_margins().values()]
+        snap["arena_quant_tau_margin_max"] = \
+            float(max(margins)) if margins else 0.0
+        snap["arena_quant_tau_margin_mean"] = \
+            float(np.mean(margins)) if margins else 0.0
         return snap
 
     def dump_trace(self, path: Optional[str] = None) -> str:

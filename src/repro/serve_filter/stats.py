@@ -37,7 +37,11 @@ Each line is one snapshot. The load-bearing keys:
   occupancy, holes, dead bitset words, compactions, growths) for the
   grouped megabatch arenas; ``arena_tile_cache_hits``/``_misses`` count
   the grouped dispatches whose per-tile weight gather the arena's
-  tile-signature cache spared or paid (always on).
+  tile-signature cache spared or paid (always on); ``tenant_device_mb``
+  is the device memory entries hold outside the arenas, padding
+  included, and
+  ``arena_quant_tau_margin_max``/``_mean`` how far calibration lowered
+  the quantized tenants' thresholds, in logits.
 
 Per-tenant drift
 ----------------

@@ -89,6 +89,10 @@ SNAPSHOT_KEYS = {
     # compressed arenas (quantized tenant state)
     "arena_quant_mb", "tenants_per_gb",
     "arena_tenants_int8", "arena_tenants_fp32", "arena_tenants_int4",
+    # what quantization costs: entries' own device arrays, and how far
+    # calibration lowered the quantized thresholds
+    "tenant_device_mb", "arena_quant_tau_margin_max",
+    "arena_quant_tau_margin_mean",
 }
 
 TENANT_KEYS = {
